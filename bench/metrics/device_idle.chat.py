@@ -1,0 +1,6 @@
+"""Share of the traced window with no operation on the device (trace)."""
+from bench.metrics import idle
+
+
+def read(ctx):
+    return idle(ctx)
