@@ -223,7 +223,10 @@ def main(argv=None):
                       f"decode {st['decode_dispatches']} dispatches / "
                       f"{st['decode_host_syncs']} host syncs "
                       f"({st['decode_host_syncs'] / max(1, n_tok):.3f} "
-                      f"syncs/token)")
+                      f"syncs/token), queue wait "
+                      f"{st['queue_wait_s_sum'] / max(1, st['admitted']):.4f}"
+                      f" s mean over {st['admitted']} admitted, decode "
+                      f"occupancy {st['decode_occupancy']:.0%}")
         else:
             out = eng.generate(prompts[:args.batch], args.new_tokens)
             dt = time.time() - t0

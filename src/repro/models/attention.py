@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import dense_init, rmsnorm_nop, apply_rope, init_rmsnorm, rmsnorm
+from repro.models.layers import (dense_init, rmsnorm_nop, apply_rope,
+                                 init_rmsnorm, rmsnorm, scoped)
 from repro.sharding.ctx import (constrain_paged_kv, constrain_paged_latent,
                                 replicate_update)
 
@@ -263,11 +264,12 @@ def apply_attention(cfg, p, x, *, positions, mode="train", cache=None,
     window = cfg.swa_window
     rope = causal if rope is None else rope
 
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    if "bq" in p:
-        q = q + p["bq"].astype(dt)
-    if cfg.qk_norm:
-        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+    with jax.named_scope("attn.qkv"):
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+        if "bq" in p:
+            q = q + p["bq"].astype(dt)
+        if cfg.qk_norm:
+            q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
 
     if paged is not None:
         if mode != "decode" or not causal:
@@ -275,51 +277,52 @@ def apply_attention(cfg, p, x, *, positions, mode="train", cache=None,
                              "self-attention only")
         pos2 = _pos2d(positions)
         src = kv_src if kv_src is not None else x
-        k = jnp.einsum("bsd,dhk->bshk", src, p["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", src, p["wv"].astype(dt))
-        if "bk" in p:
-            k = k + p["bk"].astype(dt)
-            v = v + p["bv"].astype(dt)
-        if cfg.qk_norm:
-            k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-        if rope:
-            k = apply_rope(k, pos2, cfg.rope_theta)
-            q = apply_rope(q, pos2, cfg.rope_theta)
-        # pin the UPDATE replicated before the scatter: rope's
-        # split/concat along a model-sharded head_dim otherwise leaves
-        # GSPMD free to partition the scatter update in a way that
-        # miscombines the halves inside the layer scan (observed on the
-        # CPU SPMD partitioner); host mesh: no-op
-        k = replicate_update(k)
-        v = replicate_update(v)
-        k_pool = _paged_append(cache["k"], paged, pos2, k)
-        v_pool = _paged_append(cache["v"], paged, pos2, v)
+        with jax.named_scope("attn.qkv"):
+            k = jnp.einsum("bsd,dhk->bshk", src, p["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", src, p["wv"].astype(dt))
+            if "bk" in p:
+                k = k + p["bk"].astype(dt)
+                v = v + p["bv"].astype(dt)
+            if cfg.qk_norm:
+                k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
+            if rope:
+                k = apply_rope(k, pos2, cfg.rope_theta)
+                q = apply_rope(q, pos2, cfg.rope_theta)
+        with jax.named_scope("attn.kv_write"):
+            # pin the UPDATE replicated before the scatter: rope's
+            # split/concat along a model-sharded head_dim otherwise
+            # leaves GSPMD free to partition the scatter update in a way
+            # that miscombines the halves inside the layer scan
+            # (observed on the CPU SPMD partitioner); host mesh: no-op
+            k = replicate_update(k)
+            v = replicate_update(v)
+            k_pool = _paged_append(cache["k"], paged, pos2, k)
+            v_pool = _paged_append(cache["v"], paged, pos2, v)
         if cfg.decode_kernel == "pallas":
             # fused path: the page-table gather never materialises —
             # the kernel's BlockSpec index map streams pages from the
             # pool into the online-softmax loop
             from repro.kernels.paged_decode import paged_flash_decode
-            out = paged_flash_decode(
-                q, k_pool.astype(dt), v_pool.astype(dt),
-                paged.page_table, pos2, page_size=paged.page_size,
-                window=window)
+            with jax.named_scope("attn.kv_read"):
+                out = paged_flash_decode(
+                    q, k_pool.astype(dt), v_pool.astype(dt),
+                    paged.page_table, pos2, page_size=paged.page_size,
+                    window=window)
         else:
             # spec-aware read: keep the pool's "model" sharding (heads
             # or head_dim) pinned through the page-table gather under a
             # serve topology — a no-op on the host mesh
-            k_full, kv_positions = paged_read(k_pool, paged)
-            v_full, _ = paged_read(v_pool, paged)
-            k_full = constrain_paged_kv(k_full)
-            v_full = constrain_paged_kv(v_full)
-            out = masked_attention(q, k_full.astype(dt), v_full.astype(dt),
-                                   q_positions=pos2,
-                                   kv_positions=kv_positions,
-                                   window=window)
-        _, head_mask = _padded_heads(cfg)
-        if head_mask is not None:
-            out = out * jnp.asarray(head_mask, dt)[None, None, :, None]
-        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-        return out, {"k": k_pool, "v": v_pool}
+            with jax.named_scope("attn.kv_read"):
+                k_full, kv_positions = paged_read(k_pool, paged)
+                v_full, _ = paged_read(v_pool, paged)
+                k_full = constrain_paged_kv(k_full).astype(dt)
+                v_full = constrain_paged_kv(v_full).astype(dt)
+            with jax.named_scope("attn.core"):
+                out = masked_attention(q, k_full, v_full,
+                                       q_positions=pos2,
+                                       kv_positions=kv_positions,
+                                       window=window)
+        return _attn_out(cfg, p, out, dt), {"k": k_pool, "v": v_pool}
 
     if mode == "decode" and kv_src is None and not causal:
         # cross-attention decode: cache holds the full encoder K/V
@@ -329,51 +332,65 @@ def apply_attention(cfg, p, x, *, positions, mode="train", cache=None,
         kv_valid = None
     else:
         src = kv_src if kv_src is not None else x
-        k = jnp.einsum("bsd,dhk->bshk", src, p["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", src, p["wv"].astype(dt))
-        if "bk" in p:
-            k = k + p["bk"].astype(dt)
-            v = v + p["bv"].astype(dt)
-        if cfg.qk_norm:
-            k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
-        if rope:
-            k = apply_rope(k, positions[None], cfg.rope_theta)
+        with jax.named_scope("attn.qkv"):
+            k = jnp.einsum("bsd,dhk->bshk", src, p["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", src, p["wv"].astype(dt))
+            if "bk" in p:
+                k = k + p["bk"].astype(dt)
+                v = v + p["bv"].astype(dt)
+            if cfg.qk_norm:
+                k = rmsnorm(p["k_norm"], k, cfg.norm_eps)
+            if rope:
+                k = apply_rope(k, positions[None], cfg.rope_theta)
 
         if mode == "train":
             new_cache = None
             kv_positions = positions
             kv_valid = None
         elif mode == "prefill":
-            new_cache = {"k": k, "v": v} if cache is None else {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cache["k"].dtype), 0, axis=1),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cache["v"].dtype), 0, axis=1)}
+            with jax.named_scope("attn.kv_write"):
+                new_cache = {"k": k, "v": v} if cache is None else {
+                    "k": jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], k.astype(cache["k"].dtype), 0, axis=1),
+                    "v": jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], v.astype(cache["v"].dtype), 0, axis=1)}
             kv_positions = positions
             kv_valid = None
         else:  # decode self-attention: append to cache, attend over prefix
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), cache_pos, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), cache_pos, axis=1)
+            with jax.named_scope("attn.kv_write"):
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), cache_pos,
+                    axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), cache_pos,
+                    axis=1)
             new_cache = {"k": k_cache, "v": v_cache}
-            k, v = k_cache.astype(dt), v_cache.astype(dt)
+            with jax.named_scope("attn.kv_read"):
+                k, v = k_cache.astype(dt), v_cache.astype(dt)
             kv_positions = jnp.arange(k.shape[1])
             kv_valid = cache_pos + x.shape[1]
 
     if rope:
-        q = apply_rope(q, positions[None], cfg.rope_theta)
+        with jax.named_scope("attn.qkv"):
+            q = apply_rope(q, positions[None], cfg.rope_theta)
 
-    out = chunked_attention(
-        q, k, v, q_positions=positions, kv_positions=kv_positions,
-        causal=causal, window=window if causal else 0, kv_valid_len=kv_valid)
+    with jax.named_scope("attn.core"):
+        out = chunked_attention(
+            q, k, v, q_positions=positions, kv_positions=kv_positions,
+            causal=causal, window=window if causal else 0,
+            kv_valid_len=kv_valid)
+    return _attn_out(cfg, p, out, dt), new_cache
+
+
+@scoped("attn.out")
+def _attn_out(cfg, p, out, dt):
+    """Zero the padded heads BEFORE wo, so their (garbage) attention
+    outputs contribute neither to the output nor to wo's gradient; then
+    the output projection."""
     _, head_mask = _padded_heads(cfg)
     if head_mask is not None:
-        # zero the padded heads BEFORE wo so their (garbage) attention
-        # outputs contribute neither to the output nor to wo's gradient
         out = out * jnp.asarray(head_mask, dt)[None, None, :, None]
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
-    return out, new_cache
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
 
 
 # --------------------------------------------------------------------------
@@ -412,6 +429,7 @@ def make_mla_cache(cfg, batch, max_len, dtype, *, pool=None):
             "krope": jnp.zeros((batch, max_len, m.qk_rope_head_dim), dtype)}
 
 
+@scoped("attn.qkv")
 def _mla_qkv(cfg, p, x, positions):
     m = cfg.mla
     dt = x.dtype
@@ -440,77 +458,95 @@ def apply_mla(cfg, p, x, *, positions, mode="train", cache=None,
         # absorbed decode against the paged latent pool; per-query
         # causal masking (the slab path masks per chunk-end instead)
         pos2 = _pos2d(positions)
-        # same update-pinning as the GQA path: rope splits the rope-dim
-        # and rmsnorm reduces over the latent — both along axes the pool
-        # shards over "model"
-        ckv = replicate_update(ckv)
-        krope = replicate_update(krope)
-        ckv_pool = _paged_append(cache["ckv"], paged, pos2, ckv)
-        krope_pool = _paged_append(cache["krope"], paged, pos2, krope)
-        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, p["w_uk"].astype(dt))
+        with jax.named_scope("attn.kv_write"):
+            # same update-pinning as the GQA path: rope splits the
+            # rope-dim and rmsnorm reduces over the latent — both along
+            # axes the pool shards over "model"
+            ckv = replicate_update(ckv)
+            krope = replicate_update(krope)
+            ckv_pool = _paged_append(cache["ckv"], paged, pos2, ckv)
+            krope_pool = _paged_append(cache["krope"], paged, pos2, krope)
+        with jax.named_scope("attn.qkv"):
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope,
+                               p["w_uk"].astype(dt))
         scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
         if cfg.decode_kernel == "pallas":
             from repro.kernels.paged_decode import paged_flash_decode_mla
-            out_lat = paged_flash_decode_mla(
-                q_lat, q_rope, ckv_pool.astype(dt),
-                krope_pool.astype(dt), paged.page_table, pos2,
-                page_size=paged.page_size, scale=scale,
-                window=cfg.swa_window)
+            with jax.named_scope("attn.kv_read"):
+                out_lat = paged_flash_decode_mla(
+                    q_lat, q_rope, ckv_pool.astype(dt),
+                    krope_pool.astype(dt), paged.page_table, pos2,
+                    page_size=paged.page_size, scale=scale,
+                    window=cfg.swa_window)
         else:
-            ckv_c, kv_positions = paged_read(ckv_pool, paged)
-            krope_c, _ = paged_read(krope_pool, paged)
-            ckv_c = constrain_paged_latent(ckv_c)
-            krope_c = constrain_paged_latent(krope_c)
-            ckv_c, krope_c = ckv_c.astype(dt), krope_c.astype(dt)
-            scores = (jnp.einsum("bshr,btr->bhst", q_lat, ckv_c,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bshk,btk->bhst", q_rope, krope_c,
-                                   preferred_element_type=jnp.float32))
-            scores = scores * scale
-            mask = kv_positions[None, None, :] <= pos2[:, :, None]
-            if cfg.swa_window:
-                mask &= kv_positions[None, None, :] > pos2[:, :, None] \
-                    - cfg.swa_window
-            scores = jnp.where(mask[:, None], scores, NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out_lat = jnp.einsum("bhst,btr->bshr", probs.astype(dt), ckv_c)
-        out = jnp.einsum("bshr,rhv->bshv", out_lat, p["w_uv"].astype(dt))
-        out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
-        return out, {"ckv": ckv_pool, "krope": krope_pool}
+            with jax.named_scope("attn.kv_read"):
+                ckv_c, kv_positions = paged_read(ckv_pool, paged)
+                krope_c, _ = paged_read(krope_pool, paged)
+                ckv_c = constrain_paged_latent(ckv_c).astype(dt)
+                krope_c = constrain_paged_latent(krope_c).astype(dt)
+            with jax.named_scope("attn.core"):
+                scores = (jnp.einsum("bshr,btr->bhst", q_lat, ckv_c,
+                                     preferred_element_type=jnp.float32)
+                          + jnp.einsum("bshk,btk->bhst", q_rope, krope_c,
+                                       preferred_element_type=jnp.float32))
+                scores = scores * scale
+                mask = kv_positions[None, None, :] <= pos2[:, :, None]
+                if cfg.swa_window:
+                    mask &= kv_positions[None, None, :] \
+                        > pos2[:, :, None] - cfg.swa_window
+                scores = jnp.where(mask[:, None], scores, NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out_lat = jnp.einsum("bhst,btr->bshr", probs.astype(dt),
+                                     ckv_c)
+        return (_mla_out(p, out_lat, dt),
+                {"ckv": ckv_pool, "krope": krope_pool})
 
     if mode in ("train", "prefill"):
         # expand latent to per-head K/V; chunked attention as usual
-        k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["w_uk"].astype(dt))
-        v = jnp.einsum("bsr,rhv->bshv", ckv, p["w_uv"].astype(dt))
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(krope[:, :, None, :],
-                                      k_nope.shape[:3] + (m.qk_rope_head_dim,))],
-            axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = chunked_attention(q, k, v, q_positions=positions,
-                                kv_positions=positions, causal=True,
-                                window=cfg.swa_window)
+        with jax.named_scope("attn.qkv"):
+            k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["w_uk"].astype(dt))
+            v = jnp.einsum("bsr,rhv->bshv", ckv, p["w_uv"].astype(dt))
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(
+                    krope[:, :, None, :],
+                    k_nope.shape[:3] + (m.qk_rope_head_dim,))],
+                axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        with jax.named_scope("attn.core"):
+            out = chunked_attention(q, k, v, q_positions=positions,
+                                    kv_positions=positions, causal=True,
+                                    window=cfg.swa_window)
         new_cache = None
         if mode == "prefill":
-            new_cache = {
-                "ckv": jax.lax.dynamic_update_slice_in_dim(
-                    cache["ckv"], ckv.astype(cache["ckv"].dtype), 0, axis=1),
-                "krope": jax.lax.dynamic_update_slice_in_dim(
-                    cache["krope"], krope.astype(cache["krope"].dtype), 0,
-                    axis=1)}
-    else:
-        # absorbed decode: score/attend in the 512-dim latent space
+            with jax.named_scope("attn.kv_write"):
+                new_cache = {
+                    "ckv": jax.lax.dynamic_update_slice_in_dim(
+                        cache["ckv"], ckv.astype(cache["ckv"].dtype), 0,
+                        axis=1),
+                    "krope": jax.lax.dynamic_update_slice_in_dim(
+                        cache["krope"], krope.astype(cache["krope"].dtype),
+                        0, axis=1)}
+        with jax.named_scope("attn.out"):
+            out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
+        return out, new_cache
+
+    # absorbed decode: score/attend in the 512-dim latent space
+    with jax.named_scope("attn.kv_write"):
         ckv_c = jax.lax.dynamic_update_slice_in_dim(
             cache["ckv"], ckv.astype(cache["ckv"].dtype), cache_pos, axis=1)
         krope_c = jax.lax.dynamic_update_slice_in_dim(
             cache["krope"], krope.astype(cache["krope"].dtype), cache_pos,
             axis=1)
-        new_cache = {"ckv": ckv_c, "krope": krope_c}
-        T = ckv_c.shape[1]
+    new_cache = {"ckv": ckv_c, "krope": krope_c}
+    T = ckv_c.shape[1]
+    with jax.named_scope("attn.qkv"):
         q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, p["w_uk"].astype(dt))
-        scores = (jnp.einsum("bshr,btr->bhst", q_lat, ckv_c.astype(dt),
+    with jax.named_scope("attn.kv_read"):
+        ckv_c, krope_c = ckv_c.astype(dt), krope_c.astype(dt)
+    with jax.named_scope("attn.core"):
+        scores = (jnp.einsum("bshr,btr->bhst", q_lat, ckv_c,
                              preferred_element_type=jnp.float32)
-                  + jnp.einsum("bshk,btk->bhst", q_rope, krope_c.astype(dt),
+                  + jnp.einsum("bshk,btk->bhst", q_rope, krope_c,
                                preferred_element_type=jnp.float32))
         scores = scores / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
         kv_positions = jnp.arange(T)
@@ -523,12 +559,15 @@ def apply_mla(cfg, p, x, *, positions, mode="train", cache=None,
                            - cfg.swa_window)
         scores = jnp.where(mask[None, None], scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        out_lat = jnp.einsum("bhst,btr->bshr", probs.astype(dt),
-                             ckv_c.astype(dt))
-        out = jnp.einsum("bshr,rhv->bshv", out_lat, p["w_uv"].astype(dt))
+        out_lat = jnp.einsum("bhst,btr->bshr", probs.astype(dt), ckv_c)
+    return _mla_out(p, out_lat, dt), new_cache
 
-    out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
-    return out, new_cache
+
+@scoped("attn.out")
+def _mla_out(p, out_lat, dt):
+    """Latent attention output -> per-head values -> model width."""
+    out = jnp.einsum("bshr,rhv->bshv", out_lat, p["w_uv"].astype(dt))
+    return jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
 
 
 def mla_scale_note(cfg):

@@ -8,6 +8,8 @@ documented adaptation from the paper's fp32-on-CPU setup).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,21 @@ def truncated_normal(key, shape, std, dtype=jnp.float32):
 def dense_init(key, d_in, d_out, *, std=None, dtype=jnp.float32):
     std = std if std is not None else 1.0 / np.sqrt(d_in)
     return truncated_normal(key, (d_in, d_out), std, dtype)
+
+
+def scoped(name):
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    its operations carry ``name`` in their ``op_name`` metadata and a
+    profiler trace can attribute device time to it.  Enters a fresh
+    scope per call: ``named_scope``'s own decorator form shares one
+    context object between calls, which nested tracing would corrupt."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return run
+    return deco
 
 
 # --------------------------------------------------------------------------

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from repro.models import transformer as tfm
 from repro.models.layers import (
     init_embed, apply_embed, init_rmsnorm, rmsnorm, dense_init,
-    truncated_normal)
+    truncated_normal, scoped)
 from repro.sharding.ctx import constrain_bsd, constrain_logits
 
 VISION_EMBED_DIM = 1024      # CLIP ViT-L/14-336 output width (stubbed)
@@ -85,6 +85,7 @@ def init_cache(cfg, batch, max_len, dtype, *, cross_len=0, pool=None):
 # apply
 # --------------------------------------------------------------------------
 
+@scoped("lm_head")
 def _logits(cfg, params, x):
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     table = (params["embed"]["table"] if cfg.tie_embeddings
@@ -122,11 +123,12 @@ def apply_model(cfg, params, batch, *, mode="train", cache=None,
 
     # ---------- decoder input sequence ----------
     tokens = batch.get("tgt_tokens", batch.get("tokens"))
-    x = apply_embed(params["embed"], tokens, dt)
-    if cfg.frontend == "vision" and "vision_embeds" in batch:
-        vis = _vision_proj(params, batch["vision_embeds"], dt)
-        x = jnp.concatenate([vis, x], axis=1)
-    x = constrain_bsd(x)
+    with jax.named_scope("embed"):
+        x = apply_embed(params["embed"], tokens, dt)
+        if cfg.frontend == "vision" and "vision_embeds" in batch:
+            vis = _vision_proj(params, batch["vision_embeds"], dt)
+            x = jnp.concatenate([vis, x], axis=1)
+        x = constrain_bsd(x)
 
     S = x.shape[1]
     if mode == "decode":

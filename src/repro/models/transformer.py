@@ -26,7 +26,7 @@ from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.layers import (
-    init_rmsnorm, rmsnorm, init_mlp, apply_mlp, dense_init)
+    init_rmsnorm, rmsnorm, init_mlp, apply_mlp, dense_init, scoped)
 from repro.sharding.ctx import constrain_bsd
 
 
@@ -87,6 +87,7 @@ def init_layer_cache(cfg, spec, batch, max_len, dtype, *, cross=False,
     return c
 
 
+@scoped("layer")
 def apply_layer(cfg, spec, p, x, *, positions, mode, cache=None,
                 cache_pos=None, enc_out=None, causal=True, paged=None):
     """Returns (x, new_cache, aux_loss)."""
@@ -135,9 +136,11 @@ def apply_layer(cfg, spec, p, x, *, positions, mode, cache=None,
 
     h = rmsnorm(p["norm2"], x, cfg.norm_eps)
     if ffn == "moe":
-        h, aux = moe_lib.apply_moe(cfg, p["ffn"], h)
+        with jax.named_scope("moe"):
+            h, aux = moe_lib.apply_moe(cfg, p["ffn"], h)
     else:
-        h = apply_mlp(p["ffn"], h, gated=cfg.mlp_gated)
+        with jax.named_scope("mlp"):
+            h = apply_mlp(p["ffn"], h, gated=cfg.mlp_gated)
     x = x + h
     return x, new_cache, aux
 
@@ -185,6 +188,7 @@ def init_stack_cache(cfg, batch, max_len, dtype, *, cross=False,
     return cache
 
 
+@scoped("layers")
 def apply_stack(cfg, params, x, *, positions, mode, cache=None,
                 cache_pos=None, enc_out=None, causal=True, remat=False,
                 paged=None):

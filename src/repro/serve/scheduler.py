@@ -86,7 +86,24 @@ from repro.serve.sampling import (
     SamplingConfig, accept_speculative, masked_sample, sample)
 from repro.sharding import ctx as shctx
 
-__all__ = ["ServeRequest", "ContinuousScheduler"]
+__all__ = ["ServeRequest", "ContinuousScheduler", "SPANS", "SCOPES"]
+
+# Host spans the scheduler writes into a running profiler's trace, on
+# the clock of its device events (``jax.profiler.TraceAnnotation``: a
+# no-op when no profiler runs).  Nested as listed: a tick holds the
+# admission pass (which holds each admitted request's prefill and its
+# first-token sync) and the decode tick (dispatch, sync, emit).  Every
+# argument is a host value; no span reads the device.
+SPANS = ("sched.tick", "sched.admit", "sched.prefill", "sched.prefill.sync",
+         "sched.decode", "sched.decode.dispatch", "sched.decode.sync",
+         "sched.decode.emit")
+# ``jax.named_scope`` names in the serving programs' ``op_name``
+# metadata (models/ and the sampling below), so a device trace splits
+# each program's time by the part of the model step it ran
+SCOPES = ("embed", "layers", "layer", "attn.qkv", "attn.kv_write",
+          "attn.kv_read", "attn.core", "attn.out", "mlp", "moe", "lm_head",
+          "sample")
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -100,6 +117,8 @@ class ServeRequest:
     spec_steps: int = 0                # verify steps this request rode
     spec_accepted: int = 0             # draft tokens accepted for it
     out: List[int] = dataclasses.field(default_factory=list)
+    # ``time.perf_counter()`` stamps: monotonic, so the queue wait and
+    # the time to first token never go negative
     t_submit: float = 0.0
     t_first: Optional[float] = None    # time-to-first-token timestamp
     t_done: Optional[float] = None
@@ -244,6 +263,14 @@ class ContinuousScheduler:
         self.tokens_out = 0
         self.prefix_tokens_saved = 0   # prompt tokens served by aliasing
         self.prompt_tokens = 0
+        # queue wait: submit -> start of the request's prefill, summed
+        # over the `admitted` requests
+        self.admitted = 0
+        self.queue_wait_s_sum = 0.0
+        # batch occupancy: lanes x scan steps of every decode tick, and
+        # the part of them that carried a live request
+        self.decode_lane_steps = 0
+        self.decode_live_lane_steps = 0
         # speculative-decode telemetry: acceptance is accepted/offered
         # drafts over live verify steps; per-slot arrays give the
         # accepted-length profile of each lane
@@ -297,8 +324,9 @@ class ContinuousScheduler:
             out = apply_model(cfg, params, {"tokens": tokens},
                               mode="decode", cache=cache, cache_pos=pos,
                               paged=view)
-            first = sample(out["logits"][:, -1], key,
-                           sc=sc)[0].astype(jnp.int32)
+            with jax.named_scope("sample"):
+                first = sample(out["logits"][:, -1], key,
+                               sc=sc)[0].astype(jnp.int32)
             return pin(out["cache"]), first, out["hidden"][:, -1]
 
         def decode_loop_fn(params, cache, table, tok, pos, done, key):
@@ -313,9 +341,10 @@ class ContinuousScheduler:
                 out = apply_model(cfg, params, {"tokens": tok},
                                   mode="decode", cache=cache,
                                   cache_pos=pos, paged=view)
-                logits = out["logits"][:, -1]
-                key, sub = jax.random.split(key)
-                nxt = masked_sample(logits, sub, done, pad_id, sc=sc)
+                with jax.named_scope("sample"):
+                    key, sub = jax.random.split(key)
+                    nxt = masked_sample(out["logits"][:, -1], sub, done,
+                                        pad_id, sc=sc)
                 pos = pos + jnp.where(done, 0, 1)
                 if eos_id is not None:
                     done = done | (nxt == eos_id)
@@ -353,10 +382,11 @@ class ContinuousScheduler:
                 out = apply_model(cfg, params, {"tokens": chunk},
                                   mode="decode", cache=cache,
                                   cache_pos=pos, paged=view)
-                tgt = jnp.argmax(out["logits"],
-                                 axis=-1).astype(jnp.int32)      # (B, k)
-                emit, n_emit, n_acc, done_new = accept_speculative(
-                    tgt, chunk, done, pad_id, eos_id)
+                with jax.named_scope("sample"):
+                    tgt = jnp.argmax(out["logits"],
+                                     axis=-1).astype(jnp.int32)  # (B, k)
+                    emit, n_emit, n_acc, done_new = accept_speculative(
+                        tgt, chunk, done, pad_id, eos_id)
                 pos = pos + jnp.where(done, 0, n_acc + 1)
                 nxt = tgt[lanes, jnp.maximum(n_emit - 1, 0)]
                 nxt = jnp.where(done_new, jnp.int32(pad_id), nxt)[:, None]
@@ -427,7 +457,7 @@ class ContinuousScheduler:
         uid = self._uid
         self._uid += 1
         req = ServeRequest(uid, prompt, max_new_tokens, priority=priority,
-                           tenant=tenant, t_submit=time.time())
+                           tenant=tenant, t_submit=time.perf_counter())
         heapq.heappush(self._pending, (-priority, uid, req))
         self._byuid[uid] = req
         return uid
@@ -443,17 +473,20 @@ class ContinuousScheduler:
         slot is active — ONE fused decode tick (one dispatch + one host
         sync).  This is the streaming front door's pump.  Returns
         whether work remains (pending or active)."""
-        admitted = self._admit()
-        if self._active:
-            self._decode_tick()
-        elif self._pending and not admitted:
-            # nothing active and nothing admissible: the best pending
-            # request can never be served, even after prefix eviction
-            req = min(self._pending)[2]
-            raise MemoryError(
-                f"request {req.uid} ({len(req.prompt)} prompt tokens) "
-                f"cannot be admitted into an empty batch — pool too "
-                f"small ({self.kv.free_pages} free pages)")
+        with _span("sched.tick", pending=len(self._pending),
+                   active=len(self._active)):
+            admitted = self._admit()
+            if self._active:
+                self._decode_tick()
+            elif self._pending and not admitted:
+                # nothing active and nothing admissible: the best
+                # pending request can never be served, even after prefix
+                # eviction
+                req = min(self._pending)[2]
+                raise MemoryError(
+                    f"request {req.uid} ({len(req.prompt)} prompt "
+                    f"tokens) cannot be admitted into an empty batch — "
+                    f"pool too small ({self.kv.free_pages} free pages)")
         return bool(self._active or self._pending)
 
     def take_results(self) -> Dict[int, ServeRequest]:
@@ -506,6 +539,13 @@ class ContinuousScheduler:
             "ttft_s": list(self._ttft),          # window: last/current run
             "ttft_count_cum": self._ttft_n_cum,  # lifetime counters
             "ttft_sum_cum_s": self._ttft_sum_cum,
+            "admitted": self.admitted,
+            "queue_wait_s_sum": self.queue_wait_s_sum,
+            "decode_lane_steps": self.decode_lane_steps,
+            "decode_live_lane_steps": self.decode_live_lane_steps,
+            "decode_occupancy": (self.decode_live_lane_steps
+                                 / self.decode_lane_steps
+                                 if self.decode_lane_steps else 0.0),
             "prompt_tokens": self.prompt_tokens,
             "prefix_hit_tokens": self.prefix_tokens_saved,
             "prefix_hit_rate": (self.prefix_tokens_saved
@@ -590,20 +630,22 @@ class ContinuousScheduler:
         this tick instead of waiting out a full decode chunk.  Returns
         #admitted."""
         n = 0
-        while self._pending:
-            free = self._free_slots()
-            if not free:
-                break
-            req = self._next_admissible()
-            if req is None:                 # everything quota-blocked
-                break
-            if not self._try_admit(free[0], req):
-                # pool pressure: the BEST admissible request waits, and
-                # nothing below it may jump the page queue
-                heapq.heappush(self._pending,
-                               (-req.priority, req.uid, req))
-                break
-            n += 1
+        with _span("sched.admit") as span:
+            while self._pending:
+                free = self._free_slots()
+                if not free:
+                    break
+                req = self._next_admissible()
+                if req is None:                 # everything quota-blocked
+                    break
+                if not self._try_admit(free[0], req):
+                    # pool pressure: the BEST admissible request waits,
+                    # and nothing below it may jump the page queue
+                    heapq.heappush(self._pending,
+                                   (-req.priority, req.uid, req))
+                    break
+                n += 1
+            span.set_metadata(admitted=n)
         return n
 
     def _try_admit(self, slot: int, req: ServeRequest) -> bool:
@@ -641,57 +683,64 @@ class ContinuousScheduler:
         return True
 
     def _prefill(self, slot: int, req: ServeRequest, start: int = 0):
+        queued = time.perf_counter() - req.t_submit
+        self.admitted += 1
+        self.queue_wait_s_sum += queued
         C = self.prefill_chunk
         S = len(req.prompt)
-        table_row = self.kv.table([slot])
         starts = list(range(start, S, C))      # non-empty: start <= S-1
-        for s in starts[:-1]:
+        with _span("sched.prefill", uid=req.uid, prompt_len=S,
+                   prefix_tokens=start, chunks=len(starts),
+                   queued_ms=1e3 * queued):
+            table_row = self.kv.table([slot])
+            for s in starts[:-1]:
+                chunk = jnp.asarray(req.prompt[None, s:s + C])
+                cache, _ = self._prefill_fn(
+                    self.params, self.kv.slot_cache(slot), table_row, chunk,
+                    jnp.full((1,), s, jnp.int32))
+                self.kv.merge_slot_cache(slot, cache)
+                self.dispatches += 1
+                self.prefill_dispatches += 1
+            # last chunk: sampling fused into the same compiled call —
+            # no separate first-token launch
+            s = starts[-1]
+            self._key, sub = jax.random.split(self._key)
             chunk = jnp.asarray(req.prompt[None, s:s + C])
-            cache, _ = self._prefill_fn(
+            cache, first_dev, h_last = self._prefill_last_fn(
                 self.params, self.kv.slot_cache(slot), table_row, chunk,
-                jnp.full((1,), s, jnp.int32))
+                jnp.full((1,), s, jnp.int32), sub)
             self.kv.merge_slot_cache(slot, cache)
             self.dispatches += 1
             self.prefill_dispatches += 1
-        # last chunk: sampling fused into the same compiled call —
-        # no separate first-token launch
-        s = starts[-1]
-        self._key, sub = jax.random.split(self._key)
-        chunk = jnp.asarray(req.prompt[None, s:s + C])
-        cache, first_dev, h_last = self._prefill_last_fn(
-            self.params, self.kv.slot_cache(slot), table_row, chunk,
-            jnp.full((1,), s, jnp.int32), sub)
-        self.kv.merge_slot_cache(slot, cache)
-        self.dispatches += 1
-        self.prefill_dispatches += 1
-        if self.prefix is not None:
-            # index the prompt's FULL pages (decode never writes them:
-            # its first write position S lands in the next block)
-            full = S // self.kv.page_size
-            if full:
-                self.prefix.insert(req.prompt, self.kv._owned[slot][:full])
-        first = int(first_dev)                 # prefill's ONE host sync
-        self.host_syncs += 1
-        self.prefill_host_syncs += 1
-        req.t_first = time.time()
-        req.out.append(first)
-        self.tokens_out += 1
-        if (self.eos_id is not None and first == self.eos_id) \
-                or req.max_new_tokens <= 1:
-            self._retire(slot, req, active=False)
-            return
-        self._active[slot] = req
-        self._tok = self._tok.at[slot].set(first)
-        self._pos = self._pos.at[slot].set(S)
-        if self.spec_decode:
-            # seed the draft head: trunk hidden at the last prompt
-            # position pairs with `first` exactly like train-mode MTP
-            self._hid = self._hid.at[slot].set(h_last[0])
-        self._done_host[slot] = False
-        self._done = jnp.asarray(self._done_host)
+            if self.prefix is not None:
+                # index the prompt's FULL pages (decode never writes them:
+                # its first write position S lands in the next block)
+                full = S // self.kv.page_size
+                if full:
+                    self.prefix.insert(req.prompt, self.kv._owned[slot][:full])
+            with _span("sched.prefill.sync"):
+                first = int(first_dev)             # prefill's ONE host sync
+            self.host_syncs += 1
+            self.prefill_host_syncs += 1
+            req.t_first = time.perf_counter()
+            req.out.append(first)
+            self.tokens_out += 1
+            if (self.eos_id is not None and first == self.eos_id) \
+                    or req.max_new_tokens <= 1:
+                self._retire(slot, req, active=False)
+                return
+            self._active[slot] = req
+            self._tok = self._tok.at[slot].set(first)
+            self._pos = self._pos.at[slot].set(S)
+            if self.spec_decode:
+                # seed the draft head: trunk hidden at the last prompt
+                # position pairs with `first` exactly like train-mode MTP
+                self._hid = self._hid.at[slot].set(h_last[0])
+            self._done_host[slot] = False
+            self._done = jnp.asarray(self._done_host)
 
     def _retire(self, slot: int, req: ServeRequest, *, active=True):
-        req.t_done = time.time()
+        req.t_done = time.perf_counter()
         if req.ttft is not None:
             self._ttft.append(req.ttft)
             self._ttft_n_cum += 1
@@ -704,17 +753,38 @@ class ContinuousScheduler:
         self._results[req.uid] = req
 
     def _decode_tick(self):
-        if self.spec_decode:
-            self._spec_decode_tick()
-            return
+        """One fused decode tick: ONE dispatch and ONE host sync for
+        ``decode_chunk`` scan steps, then the host walks the tokens."""
+        live, steps = len(self._active), self.decode_chunk
+        self.decode_lane_steps += self.slots * steps
+        self.decode_live_lane_steps += live * steps
+        with _span("sched.decode", live=live, slots=self.slots,
+                   steps=steps):
+            with _span("sched.decode.dispatch"):
+                pulled = (self._spec_dispatch() if self.spec_decode
+                          else self._dispatch())
+            self.dispatches += 1
+            self.decode_dispatches += 1
+            with _span("sched.decode.sync"):
+                pulled = [np.asarray(a) for a in pulled]  # ONE sync/tick
+            self.host_syncs += 1
+            self.decode_host_syncs += 1
+            with _span("sched.decode.emit"):
+                if self.spec_decode:
+                    self._spec_emit(*pulled)
+                else:
+                    self._emit(*pulled)
+                # device `done` may be ahead of host bookkeeping (EOS
+                # slots we also retired); re-sync the mirror we own
+                self._done = jnp.asarray(self._done_host)
+
+    def _dispatch(self):
         out = self._decode_fn(self.params, self.kv.cache, self.kv.table(),
                               self._tok, self._pos, self._done, self._key)
         self.kv.cache, self._tok, self._pos, self._done, self._key, toks = out
-        self.dispatches += 1
-        self.decode_dispatches += 1
-        toks_np = np.asarray(toks)                     # ONE sync per tick
-        self.host_syncs += 1
-        self.decode_host_syncs += 1
+        return (toks,)
+
+    def _emit(self, toks_np):
         for slot, req in list(self._active.items()):
             finished = False
             for t in toks_np[slot]:
@@ -728,27 +798,21 @@ class ContinuousScheduler:
                     break
             if finished:
                 self._retire(slot, req)
-        # device `done` may be ahead of host bookkeeping (EOS slots we
-        # also retired above); re-sync the mirror we own
-        self._done = jnp.asarray(self._done_host)
 
-    def _spec_decode_tick(self):
-        """The speculative twin of the fused tick: same discipline (one
-        dispatch, one host sync), but each of the ``decode_chunk`` scan
-        steps emits 1..k tokens.  ``toks`` is (slots, K, k) with each
-        step's emitted tokens left-packed; ``counts`` (slots, K) says
-        how many are real (0 on done/idle lanes)."""
+    def _spec_dispatch(self):
+        """The speculative twin of ``_dispatch``: each of the
+        ``decode_chunk`` scan steps emits 1..k tokens.  ``toks`` is
+        (slots, K, k) with each step's emitted tokens left-packed;
+        ``counts`` (slots, K) says how many are real (0 on done/idle
+        lanes)."""
         out = self._spec_decode_fn(
             self.params, self.kv.cache, self.kv.table(), self._tok,
             self._pos, self._hid, self._done)
         (self.kv.cache, self._tok, self._pos, self._hid, self._done,
          toks, counts) = out
-        self.dispatches += 1
-        self.decode_dispatches += 1
-        toks_np = np.asarray(toks)                     # ONE sync per tick
-        counts_np = np.asarray(counts)                 # (same sync event)
-        self.host_syncs += 1
-        self.decode_host_syncs += 1
+        return toks, counts
+
+    def _spec_emit(self, toks_np, counts_np):
         k = self.spec_decode
         for slot, req in list(self._active.items()):
             finished = False
@@ -776,4 +840,3 @@ class ContinuousScheduler:
                     break
             if finished:
                 self._retire(slot, req)
-        self._done = jnp.asarray(self._done_host)
