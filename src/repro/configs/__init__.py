@@ -53,7 +53,10 @@ def config_for_shape(arch: str, shape_name: str) -> ModelConfig:
 
 
 def smoke_config(arch: str) -> ModelConfig:
-    """Reduced variant of the same family: 2 layers, d_model<=512, <=4 experts.
+    """Reduced variant of the same family: d_model<=512, <=4 experts, and
+    the fewest layers (2 or more) in which the scanned super-block runs
+    twice, so per-layer indexing into stacked params and caches is
+    exercised.
 
     Keeps every structural feature (GQA ratio, MLA, MoE shared/routed,
     hybrid interleave, enc-dec, frontend stub) at toy scale for CPU tests.
@@ -103,7 +106,11 @@ def smoke_config(arch: str) -> ModelConfig:
         kw["encoder_layers"] = 2
     if cfg.frontend == "vision":
         kw["num_frontend_tokens"] = 16
-    return cfg.with_overrides(**kw)
+    cfg = cfg.with_overrides(**kw)
+    prefix, pattern, n_rep = cfg.block_structure()
+    if n_rep < 2:
+        cfg = cfg.with_overrides(num_layers=len(prefix) + 2 * len(pattern))
+    return cfg
 
 
 __all__ = [

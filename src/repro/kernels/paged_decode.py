@@ -88,6 +88,24 @@ def _pin(*xs):
     return tuple(replicate_for_kernel(x) for x in xs)
 
 
+def _pages(pool, page_table, page_size, layer, *, merge_heads=False):
+    """The pool as ``(pages, page_size, features)`` and the page table
+    that addresses it, as ``models.attention.pool_pages`` reads it: a
+    scanned block's stacked pool ``(n_rep, N, ...)`` in place, at layer
+    ``layer``'s offset in the scalar-prefetch table, where that is free.
+    ``merge_heads`` folds GQA's ``(hk, hd)`` into the lane dim, which on
+    the TPU moves every element it is given: the layer's rows are sliced
+    out first, so one layer is moved and not the whole stack."""
+    from repro.models.attention import PagedView, pool_pages
+    if merge_heads:
+        if layer is not None:
+            pool = jax.lax.dynamic_index_in_dim(pool, layer, keepdims=False)
+            layer = None
+        pool = pool.reshape(pool.shape[:-2] + (-1,))
+    return pool_pages(pool, PagedView(page_table.astype(jnp.int32),
+                                      page_size, layer))
+
+
 def _row_positions(pos_row, g, seq_q, rows):
     """Per-query positions for the (g, S)-flattened row layout.
 
@@ -164,12 +182,15 @@ def _gqa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(_page_visible(pos_row, w, page_size, window))
     def _step():
         q = q_ref[...].astype(jnp.float32)               # (rows, hd)
-        k = k_ref[...].astype(jnp.float32)               # (ps, hd)
+        # the pool is read in its own dtype: round to q's first, as a
+        # cast of the whole pool to the compute dtype would
+        k = k_ref[...].astype(q_ref.dtype).astype(jnp.float32)  # (ps, hd)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (rows, ps)
         s = jnp.where(_page_mask(qpos2, w, page_size, window), s, NEG_INF)
-        _online_update(s, v_ref[...], m_scr, l_scr, acc_scr)
+        _online_update(s, v_ref[...].astype(q_ref.dtype), m_scr, l_scr,
+                       acc_scr)
 
     @pl.when(w == n_pages_per_slot - 1)
     def _done():
@@ -182,19 +203,19 @@ def _row_pad(rows):
 
 
 def paged_flash_decode(q, k_pool, v_pool, page_table, q_positions, *,
-                       page_size, window=0):
+                       page_size, window=0, layer=None):
     """Fused paged-gather + flash attention for GQA decode.
 
     q: (B, S, h, hd) — S is a decode token or a prefill chunk;
-    k_pool, v_pool: (N, hk, hd) token-major page pool;
+    k_pool, v_pool: (N, hk, hd) token-major page pool, or with `layer`
+    a scanned block's stacked (n_rep, N, hk, hd) pool, read in place;
     page_table: (B, W) int32 physical page ids (0 = trash page);
     q_positions: (B, S) per-slot logical positions.
     Returns (B, S, h, hd) in q.dtype.
     """
     B, S, h, hd = q.shape
-    hk = k_pool.shape[1]
+    hk = k_pool.shape[-2]
     g = h // hk
-    n_pages = k_pool.shape[0] // page_size
     W = page_table.shape[1]
     rows = _row_pad(g * S)
     scale = 1.0 / np.sqrt(hd)
@@ -204,15 +225,14 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, q_positions, *,
         B, hk, g * S, hd)
     if rows > g * S:
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows - g * S), (0, 0)))
-    # (N, hk, hd) -> (n_pages, page_size, hk*hd): a free reshape that
-    # puts the kv-head axis into the lane dim, so kv head h_ of a page
-    # is the (page_size, hd) block at column block h_ — a block shape
-    # the TPU tiling accepts (a squeezed head axis in the second-minor
-    # position is refused)
-    kp = k_pool.reshape(n_pages, page_size, hk * hd)
-    vp = v_pool.reshape(n_pages, page_size, hk * hd)
+    # (N, hk, hd) -> (n_pages, page_size, hk*hd): the kv-head axis goes
+    # into the lane dim, so kv head h_ of a page is the (page_size, hd)
+    # block at column block h_ — a block shape the TPU tiling accepts
+    # (a squeezed head axis in the second-minor position is refused)
+    kp, table = _pages(k_pool, page_table, page_size, layer,
+                       merge_heads=True)
+    vp, _ = _pages(v_pool, page_table, page_size, layer, merge_heads=True)
     pos = q_positions.astype(jnp.int32).reshape(B, 1, S)
-    table = page_table.astype(jnp.int32)
 
     kernel = functools.partial(
         _gqa_kernel, scale=scale, page_size=page_size, g=g, seq_q=S,
@@ -265,14 +285,15 @@ def _mla_kernel(table_ref, pos_ref, ql_ref, qr_ref, ckv_ref, kr_ref,
 
     @pl.when(_page_visible(pos_row, w, page_size, window))
     def _step():
-        ckv = ckv_ref[...].astype(jnp.float32)           # (ps, r)
+        ckv = ckv_ref[...].astype(ql_ref.dtype).astype(jnp.float32)
         # absorbed scores: latent dot + decoupled-rope dot, one page
         s = jax.lax.dot_general(
             ql_ref[...].astype(jnp.float32), ckv,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s += jax.lax.dot_general(
-            qr_ref[...].astype(jnp.float32), kr_ref[...].astype(jnp.float32),
+            qr_ref[...].astype(jnp.float32),
+            kr_ref[...].astype(qr_ref.dtype).astype(jnp.float32),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s *= scale
@@ -286,19 +307,20 @@ def _mla_kernel(table_ref, pos_ref, ql_ref, qr_ref, ckv_ref, kr_ref,
 
 def paged_flash_decode_mla(q_lat, q_rope, ckv_pool, krope_pool,
                            page_table, q_positions, *, page_size, scale,
-                           window=0):
+                           window=0, layer=None):
     """Absorbed-MLA variant: attend in the latent space against the
     compressed pool (one kv head; V is the latent itself).
 
     q_lat: (B, S, h, r) — q_nope absorbed through w_uk;
     q_rope: (B, S, h, rope_dim); ckv_pool: (N, r); krope_pool:
-    (N, rope_dim); scale — 1/sqrt(nope+rope), the caller's convention.
+    (N, rope_dim) — or with `layer` a scanned block's stacked
+    (n_rep, N, ·) pools, read in place; scale — 1/sqrt(nope+rope), the
+    caller's convention.
     Returns the latent-space output (B, S, h, r) in q_lat.dtype for the
     caller's ``w_uv`` up-projection.
     """
     B, S, h, r = q_lat.shape
     rope_dim = q_rope.shape[-1]
-    n_pages = ckv_pool.shape[0] // page_size
     W = page_table.shape[1]
     rows = _row_pad(h * S)
 
@@ -310,10 +332,9 @@ def paged_flash_decode_mla(q_lat, q_rope, ckv_pool, krope_pool,
     if rows > h * S:
         qlr = jnp.pad(qlr, ((0, 0), (0, rows - h * S), (0, 0)))
         qrr = jnp.pad(qrr, ((0, 0), (0, rows - h * S), (0, 0)))
-    ckv = ckv_pool.reshape(n_pages, page_size, r)
-    krp = krope_pool.reshape(n_pages, page_size, rope_dim)
+    ckv, table = _pages(ckv_pool, page_table, page_size, layer)
+    krp, _ = _pages(krope_pool, page_table, page_size, layer)
     pos = q_positions.astype(jnp.int32).reshape(B, 1, S)
-    table = page_table.astype(jnp.int32)
 
     kernel = functools.partial(
         _mla_kernel, scale=scale, page_size=page_size, g=h, seq_q=S,

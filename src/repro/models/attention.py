@@ -20,6 +20,9 @@ and every slot only occupies the pages it was allocated —
 ``repro.serve.kvcache`` owns allocation; this module owns the read
 path.  ``cache_pos`` is then a per-slot ``(B,)`` vector, which is what
 continuous batching needs (slots at different depths in one step).
+The layers of a scanned block keep their pools stacked ``(n_rep, N,
+...)``; the layer scan hands each layer a view that names its index,
+and the paged paths write and read that layer's rows in place.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ import numpy as np
 from repro.models.layers import (dense_init, rmsnorm_nop, apply_rope,
                                  init_rmsnorm, rmsnorm, scoped)
 from repro.sharding.ctx import (constrain_paged_kv, constrain_paged_latent,
-                                replicate_update)
+                                replicate_update, serve_model_sharded)
 
 NEG_INF = -1e30
 
@@ -45,9 +48,14 @@ class PagedView(NamedTuple):
                  used both for never-allocated blocks and as the write
                  sink of idle slots, whose table rows are all zero).
     page_size  — tokens per page; static under jit (close over it).
+    layer      — None: a cache leaf is one layer's pool (N, ...).  Set
+                 by the layer scan to the layer's index in its block:
+                 the leaf is then the block's stacked pool
+                 (n_rep, N, ...), written and read in place.
     """
     page_table: Any
     page_size: int
+    layer: Any = None
 
 
 # --------------------------------------------------------------------------
@@ -159,28 +167,58 @@ def paged_write_indices(paged: PagedView, positions):
     return jnp.where(valid, phys, 0)
 
 
+def pool_rows(pool_leaf, paged: PagedView):
+    """A pool leaf as token rows ``(tokens, ...)`` and the first row of
+    ``paged``'s layer.  One layer's pool ``(N, ...)`` starts at row 0.
+    A scanned block's stacked pool ``(n_rep, N, ...)`` numbers its rows
+    layer after layer, so layer ``l`` starts at row ``l * N`` and is
+    written and read in place: ``pool_leaf[l]`` is never formed."""
+    if paged.layer is None:
+        return pool_leaf, 0
+    return (pool_leaf.reshape((-1,) + pool_leaf.shape[2:]),
+            paged.layer * pool_leaf.shape[1])
+
+
+def pool_pages(pool_leaf, paged: PagedView):
+    """The pool leaf as pages ``(pages, page_size, ...)`` and the page
+    table of ``paged``'s layer that addresses them, for a read.  Under
+    a model-sharded serve topology the layer's rows are sliced out
+    first.  Each device there holds a few heads (or a slice of
+    head_dim) of every token, a layout the gather does not read in
+    place: reading the stack would relayout every layer's pages in each
+    layer, and the kernels' replicated operands would all-gather it."""
+    if paged.layer is not None and serve_model_sharded():
+        pool_leaf = jax.lax.dynamic_index_in_dim(pool_leaf, paged.layer,
+                                                 keepdims=False)
+        paged = paged._replace(layer=None)
+    rows, first = pool_rows(pool_leaf, paged)
+    bs = paged.page_size
+    return (rows.reshape((-1, bs) + rows.shape[1:]),
+            paged.page_table + first // bs)
+
+
 def paged_read(pool_leaf, paged: PagedView):
     """Gather a slot-major view (B, L, ...) out of a token-major pool
     (N, ...), L = table_width * page_size.  Unallocated blocks gather
     the trash page and are masked by the causal/validity mask.  The
     gather is PAGE-granular — whole contiguous pages, table_width rows
     per slot — not per-token: on CPU/XLA a token-granular gather
-    scalarises and eats the fused-loop dispatch win."""
-    table = paged.page_table
-    bs = paged.page_size
+    scalarises and eats the fused-loop dispatch win.  A stacked pool's
+    layer is addressed by ``pool_pages``."""
+    pages, table = pool_pages(pool_leaf, paged)
     B, width = table.shape
-    pages = pool_leaf.reshape((pool_leaf.shape[0] // bs, bs)
-                              + pool_leaf.shape[1:])
     full = pages[table]                       # (B, width, bs, ...)
-    return (full.reshape((B, width * bs) + pool_leaf.shape[1:]),
-            jnp.arange(width * bs))
+    return (full.reshape((B, width * pages.shape[1]) + pages.shape[2:]),
+            jnp.arange(width * pages.shape[1]))
 
 
 def _paged_append(pool_leaf, paged: PagedView, positions, new):
-    """Scatter S new per-slot entries (B, S, ...) into the pool."""
-    idx = paged_write_indices(paged, positions)
+    """Scatter S new per-slot entries (B, S, ...) into the pool — a
+    stacked pool's layer rows in place (``pool_rows``)."""
+    rows, first = pool_rows(pool_leaf, paged)
+    idx = first + paged_write_indices(paged, positions).reshape(-1)
     flat = new.reshape((-1,) + new.shape[2:]).astype(pool_leaf.dtype)
-    return pool_leaf.at[idx.reshape(-1)].set(flat)
+    return rows.at[idx].set(flat).reshape(pool_leaf.shape)
 
 
 def _pos2d(positions):
@@ -257,7 +295,8 @@ def apply_attention(cfg, p, x, *, positions, mode="train", cache=None,
           yes, cross-attention no; bidirectional encoders pass rope=True).
     paged: PagedView — decode-mode only: `cache` is a token-major page
           pool, `positions` is per-slot (B, S), reads/writes go through
-          the page table.
+          the page table (a scanned layer's: its rows of the
+          block's stacked pool, `PagedView.layer`).
     """
     dt = x.dtype
     B = x.shape[0]
@@ -305,9 +344,9 @@ def apply_attention(cfg, p, x, *, positions, mode="train", cache=None,
             from repro.kernels.paged_decode import paged_flash_decode
             with jax.named_scope("attn.kv_read"):
                 out = paged_flash_decode(
-                    q, k_pool.astype(dt), v_pool.astype(dt),
-                    paged.page_table, pos2, page_size=paged.page_size,
-                    window=window)
+                    q, k_pool, v_pool, paged.page_table, pos2,
+                    page_size=paged.page_size, window=window,
+                    layer=paged.layer)
         else:
             # spec-aware read: keep the pool's "model" sharding (heads
             # or head_dim) pinned through the page-table gather under a
@@ -474,10 +513,9 @@ def apply_mla(cfg, p, x, *, positions, mode="train", cache=None,
             from repro.kernels.paged_decode import paged_flash_decode_mla
             with jax.named_scope("attn.kv_read"):
                 out_lat = paged_flash_decode_mla(
-                    q_lat, q_rope, ckv_pool.astype(dt),
-                    krope_pool.astype(dt), paged.page_table, pos2,
-                    page_size=paged.page_size, scale=scale,
-                    window=cfg.swa_window)
+                    q_lat, q_rope, ckv_pool, krope_pool, paged.page_table,
+                    pos2, page_size=paged.page_size, scale=scale,
+                    window=cfg.swa_window, layer=paged.layer)
         else:
             with jax.named_scope("attn.kv_read"):
                 ckv_c, kv_positions = paged_read(ckv_pool, paged)

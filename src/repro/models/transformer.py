@@ -14,6 +14,15 @@ Caches are pytrees mirroring the param structure:
   mamba -> {"ssm","conv"}       (B, dI, dS) / (B, dc-1, dI)
   rwkv6 -> {"state","shift_tm","shift_cm"}
 stacked with a leading (n_repeats,) axis for the scanned blocks.
+
+Under a paged view (serving) an attention/MLA cache is instead a page
+pool shared by every slot, ``(n_repeats, N, ...)`` with no slot axis.
+Those pools ride the layer scan's CARRY whole: each layer scatters its
+new entries into, and gathers its pages from, its own rows of the
+stacked leaf at the scan counter, so no layer's pool is sliced out of
+the stack and re-stacked, and the donated pool is updated in place.
+Per-slot leaves (recurrent SSM state) and slab caches are small and
+ride ``xs``/``ys`` as before.
 """
 from __future__ import annotations
 
@@ -62,6 +71,13 @@ def init_layer(cfg, key, spec, *, dense_ff=None, cross=False):
     return p
 
 
+def pooled(spec) -> bool:
+    """Whether a paged cache makes this layer's cache a page pool shared
+    by every slot (attention and MLA K/V: token-major, no slot axis)
+    rather than per-slot state (recurrent SSM)."""
+    return spec[0] == "attn"
+
+
 def init_layer_cache(cfg, spec, batch, max_len, dtype, *, cross=False,
                      cross_len=0, pool=None):
     """pool=(num_pages, page_size): attention/MLA caches become shared
@@ -69,6 +85,7 @@ def init_layer_cache(cfg, spec, batch, max_len, dtype, *, cross=False,
     pages to slots); recurrent SSM state stays per-slot (O(1) in
     context, nothing to page)."""
     mixer, _ = spec
+    pool = pool if pooled(spec) else None
     if mixer == "attn":
         c = (attn_lib.make_mla_cache(cfg, batch, max_len, dtype, pool=pool)
              if cfg.attention == "mla"
@@ -183,7 +200,7 @@ def init_stack_cache(cfg, batch, max_len, dtype, *, cross=False,
         return {f"layer{i}": mk(spec) for i, spec in enumerate(pattern)}
 
     cache["blocks"] = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (n_rep,) + x.shape).copy()
+        lambda x: jnp.broadcast_to(x[None], (n_rep,) + x.shape)
         if n_rep else x, one_block())
     return cache
 
@@ -209,31 +226,42 @@ def apply_stack(cfg, params, x, *, positions, mode, cache=None,
             new_cache["prefix"][f"layer{i}"] = nc
 
     def one_layer(spec):
-        def f(p, h, c):
+        def f(p, h, c, layer):
             h = constrain_bsd(h)   # pin batch->data on the residual stream
+            view = paged if layer is None else paged._replace(layer=layer)
             return apply_layer(cfg, spec, p, h, positions=positions,
                                mode=mode, cache=c, cache_pos=cache_pos,
-                               enc_out=enc_out, causal=causal, paged=paged)
+                               enc_out=enc_out, causal=causal, paged=view)
         # per-LAYER remat: bwd peak = one layer's residuals (the mamba /
         # wkv chunk-scan trajectories are the big ones), not a block's
         return jax.checkpoint(f) if remat else f
 
     layer_fns = [one_layer(spec) for spec in pattern]
 
-    def block_body(carry, xs):
-        h, aux_acc = carry
-        p_blk = xs[0] if has_cache else xs
-        c_blk = xs[1] if has_cache else None
-        nc_blk = {}
-        for j, spec in enumerate(pattern):
-            c = c_blk[f"layer{j}"] if has_cache else None
-            h, nc, a = layer_fns[j](p_blk[f"layer{j}"], h, c)
-            aux_acc = aux_acc + a
-            nc_blk[f"layer{j}"] = nc
-        return (h, aux_acc), (nc_blk if has_cache else None)
+    # a paged view's pools go into the carry whole
+    names = [f"layer{j}" for j in range(len(pattern))]
+    blk_cache = cache["blocks"] if has_cache else {}
+    pools = {n: blk_cache[n] for n, spec in zip(names, pattern)
+             if has_cache and paged is not None and pooled(spec)}
+    per_slot = ({n: blk_cache[n] for n in names if n not in pools}
+                if has_cache else None)
+    counter = jnp.arange(n_rep, dtype=jnp.int32) if pools else None
 
-    xs = (params["blocks"], cache["blocks"]) if has_cache else params["blocks"]
-    (x, aux), blk_caches = jax.lax.scan(block_body, (x, aux), xs)
+    def block_body(carry, xs):
+        h, aux_acc, pools = carry
+        p_blk, c_blk, layer = xs
+        pools, nc_blk = dict(pools), {}
+        for j, n in enumerate(names):
+            if n in pools:
+                h, pools[n], a = layer_fns[j](p_blk[n], h, pools[n], layer)
+            else:
+                c = c_blk[n] if has_cache else None
+                h, nc_blk[n], a = layer_fns[j](p_blk[n], h, c, None)
+            aux_acc = aux_acc + a
+        return (h, aux_acc, pools), (nc_blk if has_cache else None)
+
+    (x, aux, pools), blk_caches = jax.lax.scan(
+        block_body, (x, aux, pools), (params["blocks"], per_slot, counter))
     if has_cache:
-        new_cache["blocks"] = blk_caches
+        new_cache["blocks"] = {**blk_caches, **pools}
     return x, (new_cache if has_cache else None), aux

@@ -420,6 +420,9 @@ class ContinuousScheduler:
                     return fn(*a)
             return run
 
+        self._programs = {"prefill_chunk": prefill_chunk_fn,
+                          "prefill_last": prefill_last_fn,
+                          "decode": decode_loop_fn}
         self._prefill_fn = scoped(
             jax.jit(prefill_chunk_fn, donate_argnums=donate))
         self._prefill_last_fn = scoped(
@@ -520,10 +523,21 @@ class ContinuousScheduler:
         """The fused decode loop lowered at the engine's current state —
         ``.compile()`` it to inspect the program a decode tick runs (its
         memory, and whether the Pallas kernel is in it)."""
+        return self.lower("decode", self.params, self.kv.cache,
+                          self.kv.table(), self._tok, self._pos, self._done,
+                          self._key)
+
+    def lower(self, program: str, *args):
+        """Lower one step — ``"prefill_chunk"``, ``"prefill_last"`` or
+        ``"decode"`` — at ``args`` (``(params, cache, ...)`` as the
+        scheduler passes them; ``jax.ShapeDtypeStruct``s placed on the
+        devices to compile for will do), under the serve topology and
+        with the cache donated as on a chip, whatever the default
+        backend.  The programs close over the configuration, page size
+        and decode chunk, not over the pool's size."""
+        fn = jax.jit(self._programs[program], donate_argnums=(1,))
         with shctx.serve_topology(self._topo):
-            return self._decode_jit.lower(
-                self.params, self.kv.cache, self.kv.table(), self._tok,
-                self._pos, self._done, self._key)
+            return fn.lower(*args)
 
     def stats(self) -> dict:
         st = {

@@ -214,6 +214,12 @@ def constrain_paged_latent(x):
     return constrain(x, None, None, "model")
 
 
+def serve_model_sharded() -> bool:
+    """Whether a serve topology shards the model (and the paged pool's
+    feature axes) over more than one device."""
+    return _serve_model_size() > 1
+
+
 def replicate_for_kernel(x):
     """Pin a Pallas interpret-mode kernel operand (or its result) fully
     replicated under a serve topology.  The interpreter lowers the grid
@@ -225,7 +231,7 @@ def replicate_for_kernel(x):
     hands; the pool STORAGE stays model-sharded (the pin inserts an
     all-gather at the consumption point, the analogue of the gathered
     view the XLA reference path materialises).  Host mesh: no-op."""
-    if _serve_model_size() <= 1:
+    if not serve_model_sharded():
         return x
     return constrain(x, *([None] * x.ndim))
 

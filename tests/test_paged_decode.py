@@ -14,7 +14,7 @@ from repro.configs import smoke_config
 from repro.core import perf_model
 from repro.kernels.paged_decode import (paged_flash_decode,
                                         paged_flash_decode_mla)
-from repro.models import init_model
+from repro.models import apply_model, init_cache, init_model
 from repro.models.attention import (PagedView, masked_attention,
                                     paged_read, _paged_append)
 from repro.serve import ContinuousScheduler, make_engine
@@ -196,6 +196,88 @@ def test_paged_append_trash_sink_does_not_leak():
 
 
 # --------------------------------------------------------------------------
+# the stacked pool: each scanned layer writes and reads its own rows
+# --------------------------------------------------------------------------
+
+# smoke configs deep enough that the layer scan runs three times
+# (deepseek-v3: one dense prefix layer + 3; jamba: 3 x mamba/attn)
+STACK_LAYERS = {"qwen3-1.7b": 3, "deepseek-v3-671b": 4,
+                "jamba-v0.1-52b": 6}
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+@pytest.mark.parametrize("arch", sorted(STACK_LAYERS))
+def test_paged_step_writes_each_layer_in_place(arch, kernel):
+    """One paged decode step against the slab decode of the same
+    history: each layer's pool rows change exactly at the written
+    tokens, to that layer's new K/V (the slab's), and nowhere else —
+    unallocated pages and the trash page included — and the logits are
+    the slab's."""
+    cfg = _cfg(arch, num_layers=STACK_LAYERS[arch], decode_kernel=kernel)
+    assert cfg.block_structure()[2] == 3
+    params = init_model(cfg, KEY)
+    B, ps, W, P = 2, 8, 4, 13                   # write at position 13
+    table = np.asarray([[6, 2, 0, 0], [3, 8, 0, 0]], np.int32)
+    n_pages = 9
+    slab = init_cache(cfg, B, W * ps, jnp.float32)
+    pool = init_cache(cfg, B, W * ps, jnp.float32, pool=(n_pages, ps))
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 64))
+    slab = jax.tree_util.tree_map(
+        lambda x: jax.random.normal(next(keys), x.shape, x.dtype), slab)
+
+    def fill(path, p, s):
+        """The slab's history at the table's pages; random elsewhere."""
+        if p.shape == s.shape:                 # per-slot (SSM) state
+            return s
+        out = np.array(jax.random.normal(next(keys), p.shape, p.dtype))
+        stacked = jax.tree_util.keystr(path).startswith("['blocks']")
+        o, s = (out, np.asarray(s)) if stacked else (out[None],
+                                                     np.asarray(s)[None])
+        for b in range(B):
+            for w in range(2):              # the pages holding 0..P
+                o[:, table[b, w] * ps:(table[b, w] + 1) * ps] = \
+                    s[:, b, w * ps:(w + 1) * ps]
+        return jnp.asarray(out)
+    pool = jax.tree_util.tree_map_with_path(fill, pool, slab)
+
+    tok = jnp.asarray([[5], [9]], jnp.int32)
+    want = apply_model(cfg, params, {"tokens": tok}, mode="decode",
+                       cache=slab, cache_pos=P)
+    got = apply_model(cfg, params, {"tokens": tok}, mode="decode",
+                      cache=pool, cache_pos=jnp.full((B,), P, jnp.int32),
+                      paged=PagedView(jnp.asarray(table), ps))
+    written = [table[b, P // ps] * ps + P % ps for b in range(B)]
+    for layer in ("prefix", "blocks"):
+        for name in pool[layer]:
+            for old, new, ref in zip(
+                    *(jax.tree_util.tree_leaves(t[layer][name])
+                      for t in (pool, got["cache"], want["cache"]))):
+                old, new, ref = map(np.asarray, (old, new, ref))
+                if old.shape == ref.shape:     # per-slot: the slab's
+                    np.testing.assert_allclose(new, ref, rtol=1e-5,
+                                               atol=1e-5)
+                    continue
+                if layer == "prefix":          # one layer, unstacked
+                    old, new, ref = old[None], new[None], ref[None]
+                for l in range(old.shape[0]):
+                    keep = np.ones(old.shape[1], bool)
+                    keep[written] = False
+                    np.testing.assert_array_equal(
+                        new[l, keep], old[l, keep],
+                        err_msg=f"{layer}/{name} layer {l}: unwritten rows")
+                    # rope at (B, S) positions rounds apart from the
+                    # slab's (S,) ones in the last bits; another layer's
+                    # entries would differ by O(1)
+                    np.testing.assert_allclose(
+                        new[l, written], ref[l, :, P], rtol=1e-5,
+                        atol=1e-5,
+                        err_msg=f"{layer}/{name} layer {l}: new entries")
+    np.testing.assert_allclose(np.asarray(got["logits"]),
+                               np.asarray(want["logits"]),
+                               rtol=1e-5, atol=1e-5)
+
+
+# --------------------------------------------------------------------------
 # greedy equality: pallas == xla through the engine (host)
 # --------------------------------------------------------------------------
 
@@ -250,6 +332,43 @@ def test_mesh_engine_pallas_matches_host_xla(arch):
     genuinely model-sharded (the kernel pins its OPERANDS replicated,
     never the pool storage)."""
     out = run_with_devices(MESH_PALLAS_SNIPPET.format(arch=arch))
+    assert "OK" in out
+
+
+MESH_PALLAS_GATHER_SNIPPET = """
+import re, jax, numpy as np
+from repro.configs import smoke_config
+from repro.models import init_model
+from repro.launch.mesh import make_serve_mesh
+from repro.serve import make_engine
+
+cfg = smoke_config({arch!r}).with_overrides(dtype="float32",
+                                            decode_kernel="pallas")
+eng = make_engine(cfg, init_model(cfg, jax.random.PRNGKey(3)),
+                  engine="continuous", batch_size=2, max_len=64,
+                  mesh=make_serve_mesh(2, 4))
+stacked = jax.tree_util.tree_leaves(eng.kv.cache["blocks"])
+n_rep = stacked[0].shape[0]
+assert n_rep >= 2, n_rep
+gathered = []
+for line in eng.lower_decode().compile().as_text().splitlines():
+    if re.search(r" all-gather(-start)?\\(", line) and "attn.kv_read" in line:
+        kind = line.split(" = ", 1)[1].split(" all-gather")[0]
+        gathered += [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dims in re.findall(r"\\[([\\d,]*)\\]", kind)]
+assert gathered, "no all-gather: the kernel operands are not replicated"
+assert max(gathered) <= max(x.size // n_rep for x in stacked), gathered
+print("OK", {arch!r})
+"""
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "deepseek-v3-671b"])
+def test_mesh_pallas_gathers_one_layer_of_the_pool(arch):
+    """The kernel's operands are replicated on the serve mesh, so each
+    layer all-gathers what it hands the kernel (``attn.kv_read``): one
+    layer's rows of the scanned block's stacked pool, never the whole
+    stack."""
+    out = run_with_devices(MESH_PALLAS_GATHER_SNIPPET.format(arch=arch))
     assert "OK" in out
 
 
