@@ -39,6 +39,12 @@ class MoEConfig:
     # selection biased by a non-gradient balance term, weights normalised
     # over the selected experts)
     router_type: str = "softmax"
+    # softmax router: renormalise the top-k scores to sum to 1 before
+    # weighting the experts.  Every softmax-routed config here sets it
+    # false, as published (each routed expert weighted by its softmax
+    # score over all experts); the default keeps the renormalising
+    # router for configs built without the key (bench/model.py's)
+    norm_topk_prob: bool = True
 
 
 @dataclasses.dataclass(frozen=True)

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import (dense_init, rmsnorm_nop, apply_rope,
-                                 init_rmsnorm, rmsnorm, scoped)
+                                 init_rmsnorm, out_proj, rmsnorm, scoped)
 from repro.sharding.ctx import (constrain_paged_kv, constrain_paged_latent,
                                 replicate_update, serve_model_sharded)
 
@@ -429,7 +429,7 @@ def _attn_out(cfg, p, out, dt):
     _, head_mask = _padded_heads(cfg)
     if head_mask is not None:
         out = out * jnp.asarray(head_mask, dt)[None, None, :, None]
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    return out_proj(out, p["wo"].astype(dt), "bshk,hkd->bsd")
 
 
 # --------------------------------------------------------------------------
@@ -565,7 +565,7 @@ def apply_mla(cfg, p, x, *, positions, mode="train", cache=None,
                         cache["krope"], krope.astype(cache["krope"].dtype),
                         0, axis=1)}
         with jax.named_scope("attn.out"):
-            out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
+            out = out_proj(out, p["wo"].astype(dt), "bshv,hvd->bsd")
         return out, new_cache
 
     # absorbed decode: score/attend in the 512-dim latent space
@@ -605,7 +605,7 @@ def apply_mla(cfg, p, x, *, positions, mode="train", cache=None,
 def _mla_out(p, out_lat, dt):
     """Latent attention output -> per-head values -> model width."""
     out = jnp.einsum("bshr,rhv->bshv", out_lat, p["w_uv"].astype(dt))
-    return jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
+    return out_proj(out, p["wo"].astype(dt), "bshv,hvd->bsd")
 
 
 def mla_scale_note(cfg):

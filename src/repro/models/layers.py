@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.sharding import ctx as shctx
+
 
 def truncated_normal(key, shape, std, dtype=jnp.float32):
     return std * jax.random.truncated_normal(key, -3.0, 3.0, shape, dtype)
@@ -97,7 +99,24 @@ def apply_mlp(p, x, gated=True):
         h = jax.nn.silu(x @ p["w_gate"].astype(dt)) * up
     else:
         h = jax.nn.gelu(up)
-    return h @ p["w_down"].astype(dt)
+    return out_proj(h, p["w_down"].astype(dt))
+
+
+def out_proj(x, w, eq=None):
+    """A block's output projection in ``x``'s dtype: ``x @ w``, or
+    ``einsum(eq, x, w)``.  Under a model-sharded serve topology its
+    contracting axis (the heads, or the FFN width) is split over
+    "model": each device's partial product would be rounded to ``x``'s
+    dtype before the all-reduce sums them, and in bfloat16 that moves
+    the logits by ulps, enough to flip a near-tied expert choice or
+    greedy token.  There the partial products and their sum stay in
+    float32 and are rounded once, as on one device."""
+    if not shctx.serve_model_sharded():
+        return x @ w if eq is None else jnp.einsum(eq, x, w)
+    f32 = jnp.float32
+    y = (jnp.matmul(x, w, preferred_element_type=f32) if eq is None
+         else jnp.einsum(eq, x, w, preferred_element_type=f32))
+    return y.astype(x.dtype)
 
 
 # --------------------------------------------------------------------------

@@ -20,11 +20,23 @@ Two dispatch implementations, numerically identical:
       replicated over "model", so no collective is needed there);
     - expert FFNs run as dense batched matmuls on local shards;
     - the combine retraces the path and finishes with a psum over
-      "model" (which merges with the layer's tensor-parallel reduce).
+      "model" (which merges with the layer's tensor-parallel reduce);
+      serving sums in float32 and rounds once, as one device does.
 
-Router: softmax over experts, top-k, renormalised weights, plus the
-Switch-style load-balance auxiliary loss (coefficient in MoEConfig).
-Shared experts (DeepSeek) run densely on every token outside shard_map.
+Router: softmax over experts and the top-k, weighted by their softmax
+scores, renormalised over the k where ``MoEConfig.norm_topk_prob`` says
+so (DeepSeekMoE does not), or DeepSeek-V3's sigmoid scores renormalised
+over the k; plus the Switch-style load-balance auxiliary loss
+(coefficient in MoEConfig).  Shared experts (DeepSeek) run densely on
+every token outside shard_map.
+
+Both paths name their parts with ``jax.named_scope`` under the layer's
+``moe``, so a device trace splits the layer: ``moe.route`` (router
+logits, scores, top-k), ``moe.dispatch`` (each slot's position in its
+expert, the scatter into the ``(E, C, d)`` buffers, the forward
+all-to-all), ``moe.experts`` (the batched expert FFN), ``moe.combine``
+(the gather back, the gate weighting, the reverse exchange and sums)
+and ``moe.shared``.
 """
 from __future__ import annotations
 
@@ -37,7 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map, shard_map_kwargs
 
-from repro.models.layers import dense_init, init_mlp, apply_mlp
+from repro.models.layers import dense_init, init_mlp, apply_mlp, scoped
 from repro.sharding import ctx as shctx
 from repro.sharding.ctx import constrain_ecd, constrain_tokens
 
@@ -73,6 +85,7 @@ def init_moe(cfg, key):
     return p
 
 
+@scoped("moe.route")
 def _routing(cfg, p, xf):
     """xf: (N, d) -> (top-k weights (N,k), top-k idx (N,k), aux loss)."""
     m = cfg.moe
@@ -90,7 +103,8 @@ def _routing(cfg, p, xf):
     else:
         probs = jax.nn.softmax(logits, axis=-1)                # (N, E)
         top_w, top_idx = jax.lax.top_k(probs, m.top_k)         # (N, k)
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        if m.norm_topk_prob:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     # Switch aux loss: E * sum_e f_e * P_e (kept tiny for sigmoid mode —
     # V3 relies on the bias, the aux term is a sequence-level backstop)
     one_hot = jax.nn.one_hot(top_idx, m.num_experts, dtype=jnp.float32)
@@ -131,7 +145,6 @@ def apply_moe_dense(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
     """Reference scatter/gather path (single device / tests)."""
     m = cfg.moe
     B, S, d = x.shape
-    dt = x.dtype
     N = B * S
     xf = x.reshape(N, d)
 
@@ -141,44 +154,58 @@ def apply_moe_dense(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
     C = max(1, int(capacity_factor * N * k / E))
     # round capacity to a multiple of 8 lanes-friendly size
     C = min(N, -(-C // 8) * 8)
+    flat_e, safe_pos, keep, flat_w = _slots(top_idx, top_w, C, E)
+    buf = _scatter(xf, flat_e, safe_pos, keep, C, E, k)
+    out_buf = _expert_ffn(cfg, p["experts"], buf, pin=constrain_ecd)
+    y = _gather(out_buf, flat_e, safe_pos, flat_w, k).reshape(B, S, d)
 
-    # position of each (token, slot) within its expert
-    flat_e = top_idx.reshape(N * k)                             # (N*k,)
+    if m.num_shared_experts:
+        y = y + _shared(cfg, p["shared"], x)
+    return y, aux
+
+
+@scoped("moe.dispatch")
+def _slots(top_idx, top_w, C, E):
+    """Each (token, slot)'s position within its expert, in token order:
+    (flat_e, safe_pos, keep, flat_w) over the N*k slots; a slot past its
+    expert's capacity C is dropped (keep False, weight 0)."""
+    flat_e = top_idx.reshape(-1)                                # (N*k,)
     onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)         # (N*k, E)
     pos = jnp.cumsum(onehot, axis=0) - 1                        # running count
     flat_pos = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
     keep = flat_pos < C
-    flat_w = top_w.reshape(N * k) * keep
+    flat_w = top_w.reshape(-1) * keep
+    safe_pos = jnp.where(keep, flat_pos, 0)
+    return flat_e, safe_pos, keep, flat_w
 
-    # scatter tokens into (E, C, d)
+
+@scoped("moe.dispatch")
+def _scatter(xf, flat_e, safe_pos, keep, C, E, k):
+    """Tokens into their experts' (E, C, d) capacity buffers."""
+    N, d = xf.shape
+    dt = xf.dtype
     tok_idx = jnp.repeat(jnp.arange(N), k)
     buf = jnp.zeros((E, C, d), dt)
-    safe_pos = jnp.where(keep, flat_pos, 0)
     upd = constrain_tokens(xf[tok_idx] * keep[:, None].astype(dt))
     buf = buf.at[flat_e, safe_pos].add(upd, mode="drop")
-    buf = constrain_ecd(buf)       # expert-parallel layout (the all-to-all)
+    return constrain_ecd(buf)      # expert-parallel layout (the all-to-all)
 
-    # per-expert dense FFN: (E, C, d) x (E, d, f)
-    up = jnp.einsum("ecd,edf->ecf", buf, p["experts"]["w_up"].astype(dt))
-    if cfg.mlp_gated:
-        gate = jnp.einsum("ecd,edf->ecf", buf,
-                          p["experts"]["w_gate"].astype(dt))
-        h = jax.nn.silu(gate) * up
-    else:
-        h = jax.nn.gelu(up)
-    h = constrain_ecd(h)
-    out_buf = constrain_ecd(
-        jnp.einsum("ecf,efd->ecd", h, p["experts"]["w_down"].astype(dt)))
 
-    # gather back with router weights; (N*k) slots are token-major so a
-    # reshape-sum over the k slot axis recombines them
+@scoped("moe.combine")
+def _gather(out_buf, flat_e, safe_pos, flat_w, k):
+    """Expert outputs back to their tokens, weighted by the gates; the
+    (N*k) slots are token-major, so a reshape-sum over the k slot axis
+    recombines them.  Returns (N, d)."""
+    dt = out_buf.dtype
     y = constrain_tokens(out_buf[flat_e, safe_pos]
                          * flat_w[:, None].astype(dt))
-    y = y.reshape(N, k, d).sum(axis=1).reshape(B, S, d)
+    return y.reshape(-1, k, y.shape[-1]).sum(axis=1)
 
-    if m.num_shared_experts:
-        y = y + apply_mlp(p["shared"], x, gated=cfg.mlp_gated)
-    return y, aux
+
+@scoped("moe.shared")
+def _shared(cfg, p, x):
+    """The always-on shared experts: one dense MLP over every token."""
+    return apply_mlp(p, x, gated=cfg.mlp_gated)
 
 
 # ==========================================================================
@@ -210,17 +237,11 @@ def _route_local(cfg, p, xf, capacity_factor):
     top_w, top_idx, aux = _routing(cfg, p, xf)
     C = max(1, int(capacity_factor * N * k / E))
     C = min(max(N, 8), -(-C // 8) * 8)
-
-    flat_e = top_idx.reshape(N * k)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) - 1
-    flat_pos = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    keep = flat_pos < C
-    flat_w = top_w.reshape(N * k) * keep
-    safe_pos = jnp.where(keep, flat_pos, 0)
+    flat_e, safe_pos, keep, flat_w = _slots(top_idx, top_w, C, E)
     return flat_e, safe_pos, keep, flat_w, aux, C
 
 
+@scoped("moe.dispatch")
 def _pack(xf, flat_e, safe_pos, keep, C, rows, *, start=0, k=1):
     """Scatter tokens into expert rows [start, start+rows).  One scatter
     per top-k slot so the (N*k, d) token-copy tensor is never
@@ -239,11 +260,13 @@ def _pack(xf, flat_e, safe_pos, keep, C, rows, *, start=0, k=1):
     return buf, sel
 
 
+@scoped("moe.combine")
 def _combine(out, flat_e, safe_pos, flat_w, sel, k, dt, *, start=0,
              local_rows=None):
-    """Gather expert outputs back per top-k slot and weight-sum them.
-    out: (rows, C, d) local expert outputs.  ``local_rows`` overrides the
-    expert-id -> local-row mapping (default: flat_e - start)."""
+    """Gather expert outputs back per top-k slot and weight-sum them in
+    ``dt``.  out: (rows, C, d) local expert outputs.  ``local_rows``
+    overrides the expert-id -> local-row mapping (default: flat_e -
+    start)."""
     N = flat_e.shape[0] // k
     rows = local_rows if local_rows is not None else flat_e - start
     le = jnp.where(sel, rows, 0).reshape(N, k)
@@ -251,13 +274,16 @@ def _combine(out, flat_e, safe_pos, flat_w, sel, k, dt, *, start=0,
     w = (flat_w * sel).reshape(N, k)
     y = None
     for j in range(k):
-        yj = out[le[:, j], pos[:, j]] * w[:, j][:, None].astype(dt)
+        yj = out[le[:, j], pos[:, j]].astype(dt) * w[:, j][:, None].astype(dt)
         y = yj if y is None else y + yj
     return y
 
 
-def _expert_ffn(cfg, experts, buf, mi=None, f_slice=None):
-    """Dense batched FFN over a local expert buffer."""
+@scoped("moe.experts")
+def _expert_ffn(cfg, experts, buf, pin=lambda t: t):
+    """Dense batched FFN over a local expert buffer (E, C, d); ``pin``
+    lays out its hidden and output buffers (the dense path pins them to
+    the expert-parallel layout)."""
     dt = buf.dtype
     w_up = experts["w_up"].astype(dt)
     w_down = experts["w_down"].astype(dt)
@@ -268,7 +294,8 @@ def _expert_ffn(cfg, experts, buf, mi=None, f_slice=None):
                                    w_gate.astype(dt))) * up
     else:
         h = jax.nn.gelu(up)
-    return jnp.einsum("ecf,efd->ecd", h, w_down)
+    h = pin(h)
+    return pin(jnp.einsum("ecf,efd->ecd", h, w_down))
 
 
 def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
@@ -301,6 +328,10 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
     E = m.num_experts
     k = m.top_k
     has_bias = "router_bias" in p
+    # serving sums the top-k slots, spread over the ranks, in float32
+    # and rounds once, so the sum does not depend on which rank holds
+    # which expert: a mesh serves the tokens a one-device mesh serves
+    acc = jnp.float32 if shctx.get_serve_topology() is not None else dt
 
     def body(xb, router, bias, experts):
         rp = ({"router": router, "router_bias": bias}
@@ -311,38 +342,45 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
             flat_e, safe_pos, keep, flat_w, aux, C = _route_local(
                 cfg, rp, xf, capacity_factor)
             buf, _ = _pack(xf, flat_e, safe_pos, keep, C, E, k=k)
-            buf = jax.lax.all_to_all(buf, "data", split_axis=0,
-                                     concat_axis=1, tiled=True)
             mi = jax.lax.axis_index("model")
             e_loc = E // (ep_data * ep_model)
-            buf = jax.lax.dynamic_slice_in_dim(buf, mi * e_loc, e_loc, 0)
+            with jax.named_scope("moe.dispatch"):
+                buf = jax.lax.all_to_all(buf, "data", split_axis=0,
+                                         concat_axis=1, tiled=True)
+                buf = jax.lax.dynamic_slice_in_dim(buf, mi * e_loc, e_loc,
+                                                   0)
             out = _expert_ffn(cfg, experts, buf)
             if os.environ.get("REPRO_BASELINE"):
                 # pre-§Perf: pad back to all model ranks' rows and a2a
                 # ((ep_model-1)/ep_model of the reverse wire is zeros)
-                out_full = jnp.zeros((E // ep_data, C * ep_data, d), dt)
-                out_full = jax.lax.dynamic_update_slice_in_dim(
-                    out_full, out, mi * e_loc, 0)
-                out_back = jax.lax.all_to_all(
-                    out_full, "data", split_axis=1, concat_axis=0,
-                    tiled=True)
+                with jax.named_scope("moe.combine"):
+                    out_full = jnp.zeros((E // ep_data, C * ep_data, d),
+                                         dt)
+                    out_full = jax.lax.dynamic_update_slice_in_dim(
+                        out_full, out, mi * e_loc, 0)
+                    out_back = jax.lax.all_to_all(
+                        out_full, "data", split_axis=1, concat_axis=0,
+                        tiled=True)
                 y = _combine(out_back, flat_e, safe_pos, flat_w, keep, k,
-                             dt)
+                             acc)
             else:
                 # §Perf target 3: reverse a2a on the model-local slice
                 # ONLY (16x wire saving on the reverse path).
                 # out: (e_loc, C*ep_data, d) -> (ep_data*e_loc, C, d),
                 # row di*e_loc+r = expert (di*ep_model+mi)*e_loc+r of MY
                 # tokens.
-                out_back = jax.lax.all_to_all(
-                    out, "data", split_axis=1, concat_axis=0, tiled=True)
+                with jax.named_scope("moe.combine"):
+                    out_back = jax.lax.all_to_all(
+                        out, "data", split_axis=1, concat_axis=0,
+                        tiled=True)
                 mi_of_e = (flat_e // e_loc) % ep_model
                 row = ((flat_e // (e_loc * ep_model)) * e_loc
                        + flat_e % e_loc)
                 sel = keep & (mi_of_e == mi)
                 y = _combine(out_back, flat_e, safe_pos, flat_w, sel, k,
-                             dt, local_rows=row)
-            y = jax.lax.psum(y, "model")   # sum expert shards over model
+                             acc, local_rows=row)
+            with jax.named_scope("moe.combine"):
+                y = jax.lax.psum(y, "model")   # sum expert shards
             aux = jax.lax.pmean(aux, dp_ax)
         elif batch_sharded and ep_data == 1 and f_tp:
             # ---- expert-FSDP: E over 'model', weight f-shards FSDP'd
@@ -350,14 +388,10 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
             # (small) weight shards and processes its local tokens with
             # its local experts.  Gradients reduce-scatter automatically
             # (transpose of all_gather).
-            ew = {
-                "w_up": jax.lax.all_gather(experts["w_up"], "data",
-                                           axis=2, tiled=True),
-                "w_down": jax.lax.all_gather(experts["w_down"], "data",
-                                             axis=1, tiled=True)}
-            if "w_gate" in experts:
-                ew["w_gate"] = jax.lax.all_gather(experts["w_gate"], "data",
-                                                  axis=2, tiled=True)
+            with jax.named_scope("moe.experts"):
+                ew = {kk: jax.lax.all_gather(
+                    vv, "data", axis=(1 if kk == "w_down" else 2),
+                    tiled=True) for kk, vv in experts.items()}
             flat_e, safe_pos, keep, flat_w, aux, C = _route_local(
                 cfg, rp, xf, capacity_factor)
             e_loc = E // ep_model
@@ -366,9 +400,10 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
             buf_loc, sel = _pack(xf, flat_e, safe_pos, keep, C, e_loc,
                                  start=start, k=k)
             out = _expert_ffn(cfg, ew, buf_loc)
-            y = _combine(out, flat_e, safe_pos, flat_w, sel, k, dt,
+            y = _combine(out, flat_e, safe_pos, flat_w, sel, k, acc,
                          start=start)
-            y = jax.lax.psum(y, "model")
+            with jax.named_scope("moe.combine"):
+                y = jax.lax.psum(y, "model")
             aux = jax.lax.pmean(aux, dp_ax)
         else:
             # ---- replicated-token fallback (unshardable batch, e.g.
@@ -384,20 +419,21 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
                 start = (di * ep_model + mi) * e_loc
             buf_loc, sel = _pack(xf, flat_e, safe_pos, keep, C, e_loc,
                                  start=start, k=k)
+            ew = experts
             if f_tp:
-                ew = {kk: jax.lax.all_gather(
-                    vv, "data", axis=(1 if kk == "w_down" else 2),
-                    tiled=True) for kk, vv in experts.items()}
-            else:
-                ew = experts
+                with jax.named_scope("moe.experts"):
+                    ew = {kk: jax.lax.all_gather(
+                        vv, "data", axis=(1 if kk == "w_down" else 2),
+                        tiled=True) for kk, vv in experts.items()}
             out = _expert_ffn(cfg, ew, buf_loc)
-            y = _combine(out, flat_e, safe_pos, flat_w, sel, k, dt,
+            y = _combine(out, flat_e, safe_pos, flat_w, sel, k, acc,
                          start=start)
             red = ("model", "data") if ep_data > 1 else ("model",)
-            y = jax.lax.psum(y, red)
+            with jax.named_scope("moe.combine"):
+                y = jax.lax.psum(y, red)
             if has_pod:
                 aux = jax.lax.pmean(aux, "pod")
-        return y.reshape(xb.shape), aux
+        return y.astype(dt).reshape(xb.shape), aux
 
     wrapped = shard_map(
         body, mesh=mesh,
@@ -407,5 +443,5 @@ def apply_moe_ep(cfg, p, x, *, capacity_factor=CAPACITY_FACTOR):
     y, aux = wrapped(x, p["router"], p.get("router_bias"), p["experts"])
 
     if m.num_shared_experts:
-        y = y + apply_mlp(p["shared"], x, gated=cfg.mlp_gated)
+        y = y + _shared(cfg, p["shared"], x)
     return y, aux

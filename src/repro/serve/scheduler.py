@@ -101,8 +101,9 @@ SPANS = ("sched.tick", "sched.admit", "sched.prefill", "sched.prefill.sync",
 # metadata (models/ and the sampling below), so a device trace splits
 # each program's time by the part of the model step it ran
 SCOPES = ("embed", "layers", "layer", "attn.qkv", "attn.kv_write",
-          "attn.kv_read", "attn.core", "attn.out", "mlp", "moe", "lm_head",
-          "sample")
+          "attn.kv_read", "attn.core", "attn.out", "mlp", "moe", "moe.route",
+          "moe.dispatch", "moe.experts", "moe.combine", "moe.shared",
+          "lm_head", "sample")
 _span = jax.profiler.TraceAnnotation
 
 

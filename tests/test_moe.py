@@ -14,7 +14,11 @@ KEY = jax.random.PRNGKey(3)
 
 
 def test_router_topk_weights_normalised():
+    """A renormalising softmax router (``norm_topk_prob``, the default;
+    DeepSeekMoE itself does not renormalise) gives gates summing to 1."""
     cfg = smoke_config("deepseek-moe-16b")
+    cfg = cfg.with_overrides(moe=dataclasses.replace(cfg.moe,
+                                                     norm_topk_prob=True))
     p = moe_lib.init_moe(cfg, KEY)
     xf = jax.random.normal(KEY, (32, cfg.d_model))
     w, idx, aux = moe_lib._routing(cfg, {"router": p["router"]}, xf)
@@ -46,6 +50,70 @@ def test_generous_capacity_means_no_drops():
     want = want.reshape(x.shape)
     np.testing.assert_allclose(np.asarray(y_hi), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+def _moe_cfg(arch, norm):
+    cfg = smoke_config(arch)
+    if norm is not None:
+        cfg = cfg.with_overrides(moe=dataclasses.replace(
+            cfg.moe, norm_topk_prob=norm))
+    return cfg
+
+
+@pytest.mark.parametrize("arch,norm", [
+    ("deepseek-moe-16b", None),         # as published: not renormalised
+    ("deepseek-moe-16b", True),
+    ("jamba-v0.1-52b", None),           # as published: not renormalised
+], ids=["deepseek-moe-published", "deepseek-moe-renormalised", "jamba"])
+def test_routed_part_is_the_gate_weighted_sum(arch, norm):
+    """The softmax router's gates are the top-k softmax scores over all
+    experts, divided by their sum only where ``norm_topk_prob`` says so
+    (bit for bit the formula), and the layer's routed part is the
+    gate-weighted sum of the chosen experts' FFNs."""
+    cfg = _moe_cfg(arch, norm)
+    m = cfg.moe
+    assert m.norm_topk_prob == (norm if norm is not None else False)
+    p = moe_lib.init_moe(cfg, KEY)
+    x = jax.random.normal(KEY, (2, 8, cfg.d_model))
+    xf = x.reshape(-1, cfg.d_model)
+
+    probs = jax.nn.softmax(xf @ p["router"], axis=-1)
+    top, idx = jax.lax.top_k(probs, m.top_k)
+    if m.norm_topk_prob:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    w, got_idx, _ = moe_lib._routing(cfg, p, xf)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(top))
+
+    y, _ = moe_lib.apply_moe_dense(cfg, p, x, capacity_factor=64.0)
+    if m.num_shared_experts:
+        y = y - moe_lib.apply_mlp(p["shared"], x, gated=cfg.mlp_gated)
+    want = jnp.zeros_like(xf)
+    for e in range(m.num_experts):
+        ffn = moe_lib._expert_ffn(cfg, jax.tree_util.tree_map(
+            lambda t: t[e:e + 1], p["experts"]), xf[None])[0]
+        gate = jnp.sum(jnp.where(idx == e, top, 0.0), axis=-1)
+        want = want + gate[:, None] * ffn
+    np.testing.assert_allclose(np.asarray(y).reshape(xf.shape),
+                               np.asarray(want), atol=1e-4, rtol=1e-4)
+
+
+def test_sigmoid_router_weights_are_renormalised_scores():
+    """DeepSeek-V3's sigmoid router, as before: experts chosen by score
+    plus the balance bias, weighted by the bias-free scores renormalised
+    over the chosen k."""
+    cfg = smoke_config("deepseek-v3-671b")
+    p = moe_lib.init_moe(cfg, KEY)
+    p = dict(p, router_bias=jax.random.normal(
+        jax.random.PRNGKey(5), p["router_bias"].shape))
+    xf = jax.random.normal(KEY, (32, cfg.d_model))
+    scores = jax.nn.sigmoid(xf @ p["router"])
+    _, idx = jax.lax.top_k(scores + p["router_bias"][None], cfg.moe.top_k)
+    top = jnp.take_along_axis(scores, idx, axis=1)
+    top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    w, got_idx, _ = moe_lib._routing(cfg, p, xf)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(top))
 
 
 def test_tight_capacity_drops_tokens():

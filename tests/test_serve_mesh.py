@@ -214,3 +214,64 @@ def test_launcher_requests_normalisation_and_legacy_refusal():
     # the sentinel must NOT trip the refusal (0 means "--batch", not 0)
     outs = main(base + ["--engine", "legacy", "--requests", "0"])
     assert len(np.asarray(outs)) == 2
+
+
+def test_mesh_output_projection_rounds_once():
+    """An output projection whose contracting axis the serve mesh splits
+    over "model" sums float32 partial products and rounds once, so in
+    bfloat16 it equals one device's bit for bit (rounding each device's
+    partial product first put 40% of these elements an ulp apart, and
+    flipped a greedy token in the launcher test above)."""
+    out = run_with_devices("""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_serve_mesh
+    from repro.models.layers import out_proj
+    from repro.sharding import ctx as shctx
+
+    mesh = make_serve_mesh(2, 4)
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (4, 8, 512), jnp.bfloat16)
+    w = (jax.random.normal(kw, (512, 256)) * 0.05).astype(jnp.bfloat16)
+    one = np.asarray(jax.jit(out_proj)(x, w), np.float32)
+    xs = jax.device_put(x, NamedSharding(mesh, P(None, None, "model")))
+    ws = jax.device_put(w, NamedSharding(mesh, P("model", None)))
+    with shctx.serve_topology(shctx.ServeTopology.from_mesh(mesh)):
+        got = jax.jit(out_proj)(xs, ws)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32), one)
+    print("OK")
+    """)
+    assert "OK" in out
+
+
+def test_mesh_moe_combine_rounds_once():
+    """The expert-parallel MoE layer in bfloat16 gives the same bits on
+    a (2, 4) serve mesh as on a one-device one, with the top-k slots of
+    a token spread over ranks (8 experts, top 4: two per rank): the
+    serving combine sums the slots and the ranks in float32 and rounds
+    once."""
+    out = run_with_devices("""
+    import dataclasses, jax, jax.numpy as jnp, numpy as np
+    from repro.configs import smoke_config
+    from repro.launch.mesh import make_serve_mesh
+    from repro.models import moe
+    from repro.sharding import ctx as shctx
+
+    cfg = smoke_config("deepseek-moe-16b")
+    cfg = cfg.with_overrides(moe=dataclasses.replace(
+        cfg.moe, num_experts=8, top_k=4))
+    p = moe.init_moe(cfg, jax.random.PRNGKey(1))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, cfg.d_model),
+                          jnp.bfloat16)
+    outs = []
+    for shape in ((1, 1), (2, 4)):
+        topo = shctx.ServeTopology.from_mesh(make_serve_mesh(*shape))
+        with shctx.serve_topology(topo):
+            y = jax.jit(lambda p, x: moe.apply_moe(cfg, p, x)[0])(p, x)
+        assert y.dtype == jnp.bfloat16
+        outs.append(np.asarray(y, np.float32))
+    np.testing.assert_array_equal(outs[1], outs[0])
+    print("OK")
+    """)
+    assert "OK" in out

@@ -178,3 +178,19 @@ def test_serving_programs_carry_the_scopes(arch, kernel, mixer):
     # the programs keep their functions' names
     assert any(n.startswith("jit(decode_loop_fn)") for n in decode)
     assert any(n.startswith("jit(prefill_last_fn)") for n in prefill)
+
+
+MOE_SCOPES = {"moe", "moe.route", "moe.dispatch", "moe.experts",
+              "moe.combine", "moe.shared"}
+
+
+@pytest.mark.parametrize("arch", ["deepseek-moe-16b", "deepseek-v3-671b"])
+def test_decode_loop_carries_the_moe_scopes(arch):
+    """The compiled decode loop of a MoE config splits the layer's
+    ``moe`` into its route, dispatch, experts, combine and shared parts
+    (softmax and sigmoid routers alike)."""
+    cfg = _cfg(arch)
+    sch = _sched(cfg, init_model(cfg, KEY))
+    names = _op_names(sch.lower_decode().compile().as_text())
+    assert MOE_SCOPES <= set(SCOPES)
+    assert MOE_SCOPES <= _scopes_in(names)
